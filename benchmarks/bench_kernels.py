@@ -15,7 +15,11 @@ from repro.kernels.maxsim.maxsim import maxsim_pallas
 from repro.kernels.maxsim.ref import maxsim_ref
 from repro.kernels.ivf_scan.ivf_scan import ivf_scan_pallas
 from repro.kernels.ivf_scan.ref import ivf_scan_ref
-from repro.roofline.analysis import HBM_BW, PEAK_FLOPS
+from repro.roofline.analysis import device_peaks
+
+# modelled times use the published v5e peaks, not a measurement
+_PEAKS = device_peaks("TPU v5 lite")
+PEAK_FLOPS, HBM_BW = _PEAKS["bf16_flops"], _PEAKS["hbm_bw"]
 
 
 def _wall(f, *args, n=5):

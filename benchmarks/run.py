@@ -43,6 +43,9 @@ def main() -> None:
         os.environ["REPRO_BENCH_OUT_DIR"] = args.json_dir
 
     import importlib
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("suite,name,us_per_call,derived")
     for name, mod_name in SUITES:
         if args.only and args.only not in name:

@@ -32,8 +32,8 @@ def _maxsim_np(q_bow: np.ndarray, q_len: int, d_bow: np.ndarray,
                d_lens: np.ndarray, use_pallas: bool = False) -> np.ndarray:
     """q_bow (Lq, D); d_bow (K, T, D); returns (K,) fp32 MaxSim scores.
 
-    use_pallas=True routes through the TPU MaxSim kernel (interpret mode on
-    CPU); default is the jnp/XLA path.
+    use_pallas=True routes through the Pallas MaxSim kernel (compiled on
+    TPU, interpreted on CPU); default is the jnp/XLA path.
     """
     if d_bow.shape[0] == 0:
         return np.zeros((0,), np.float32)

@@ -1,18 +1,17 @@
 """Pallas TPU packed-bit asymmetric MaxSim kernel (Nardini et al. 2024).
 
-Same tiling as the full-precision MaxSim kernel (grid over document tiles,
-query matrix pinned in VMEM via a block-0 index_map), but the document tile
-arrives as sign-packed uint32 lanes — 16-32x less VMEM/HBM traffic per tile
-than bf16/fp32 tokens. Each step unpacks the (BK, T, W) lane tile to {-1,+1}
-in registers (shift + mask against a broadcasted iota; TPU requires >= 2D
-iota so the shift tensor is materialized at full rank), runs ONE MXU matmul
-(Lq x D) @ (D, BK*T), masks by doc length, reduces max-over-tokens then
-sum-over-query-tokens, and writes (BK,) scores.
+Same grid, padding and reduction as the full-precision MaxSim kernel
+(``repro.kernels.maxsim.maxsim``): query pinned in VMEM, one flattened
+(BK*Tp, W) doc tile per step, scores written as (BK, 1, 1). The tile arrives
+as sign-packed 32-bit lanes — 16-32x less VMEM/HBM traffic than bf16/fp32
+tokens. Each step expands the W words to W*32 lanes (lane c takes bit c%32
+of word c//32, via int32 shifts against a 2-D iota), maps bits to {-1, +1}
+with a select, and runs ONE MXU matmul against the query zero-padded from D
+to W*32 columns, so the pad bits of the last word contribute nothing.
 
-VMEM budget per step (defaults BK=16, T=256, W=4 i.e. D=128):
-  packed tile 16*256*4*4B = 64 KB (vs 1 MB bf16) + unpacked scratch in
-  registers — far under the 16 MB VMEM ceiling. Alignment mirrors maxsim:
-  D padded to 128 (lane), BK*T a multiple of 128, Lq padded to 8 (sublane).
+VMEM budget per step (defaults BK=16, Tp=184, W=1 i.e. D=32): packed tile
+16*184*128*4 = 1.5 MB lane-padded, the same again for the unpacked signs and
+the scores — under the 16 MB scoped VMEM default of a v5e.
 """
 from __future__ import annotations
 
@@ -20,67 +19,39 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-NEG = -1e30
+from repro.kernels.maxsim.maxsim import maxsim_tail, pad_operands, tiled_call
 
 
-def _kernel(q_ref, qmask_ref, d_ref, len_ref, out_ref, *, bk: int, t: int,
-            w: int, d: int):
-    q = q_ref[...]                                   # (Lqp, D)
-    qmask = qmask_ref[...]                           # (Lqp,)
-    packed = d_ref[...]                              # (BK, T, W) uint32
-    lens = len_ref[...]                              # (BK,)
-    lqp = q.shape[0]
-
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bk, t, w, 32), 3)
-    bits = (packed[..., None] >> shifts) & jnp.uint32(1)
-    sgn = bits.reshape(bk, t, w * 32)[..., :d]       # (BK, T, D) in {0,1}
-    sgn = sgn.astype(jnp.float32) * 2.0 - 1.0        # -> {-1, +1}
-
-    dt = sgn.reshape(bk * t, d)                      # (BK*T, D)
-    s = jax.lax.dot_general(q, dt, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Lqp, BK*T)
-    s = s.reshape(lqp, bk, t)
-    tpos = jax.lax.broadcasted_iota(jnp.int32, (lqp, bk, t), 2)
-    s = jnp.where(tpos < lens[None, :, None], s, NEG)
-    m = jnp.max(s, axis=2)                           # (Lqp, BK)
-    m = m * qmask[:, None]
-    out_ref[...] = jnp.sum(m, axis=0)                # (BK,)
+def _kernel(q_ref, qmask_ref, d_ref, len_ref, out_ref, *, bk: int, t: int):
+    words = d_ref[...]                               # (BK*Tp, W) int32
+    rows, w = words.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, w * 32), 1)
+    word = jnp.broadcast_to(words[:, :1], lane.shape)
+    for i in range(1, w):
+        word = jnp.where(lane // 32 == i, words[:, i:i + 1], word)
+    bits = (word >> (lane % 32)) & 1
+    sgn = jnp.where(bits == 1, 1.0, -1.0)            # (BK*Tp, W*32) fp32
+    s = jax.lax.dot_general(sgn, q_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    maxsim_tail(s, qmask_ref, len_ref, out_ref, bk=bk, t=t)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("d", "block_docs", "interpret"))
 def bitsim_pallas(q, q_mask, docs_packed, doc_lens, *, d: int,
-                  block_docs: int = 16, interpret: bool = True):
+                  block_docs: int = 16, interpret: bool | None = None):
     """q: (Lq, D) float; q_mask: (Lq,); docs_packed: (K, T, W) uint32 with
     W*32 >= d == D; doc_lens: (K,).
 
-    Returns (K,) fp32 asymmetric MaxSim scores. Pads Lq to 8 and K to
-    block_docs, like the full-precision maxsim kernel.
-    """
-    lq, d_dim = q.shape
-    k, t, w = docs_packed.shape
-    lqp = -(-lq // 8) * 8
-    kp = -(-k // block_docs) * block_docs
-    q = jnp.pad(q, ((0, lqp - lq), (0, 0)))
-    q_mask = jnp.pad(q_mask.astype(q.dtype), (0, lqp - lq))
-    docs_packed = jnp.pad(docs_packed.astype(jnp.uint32),
-                          ((0, kp - k), (0, 0), (0, 0)))
-    doc_lens = jnp.pad(doc_lens.astype(jnp.int32), (0, kp - k))
-
-    grid = (kp // block_docs,)
-    out = pl.pallas_call(
-        functools.partial(_kernel, bk=block_docs, t=t, w=w, d=d),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((lqp, d_dim), lambda i: (0, 0)),        # q pinned
-            pl.BlockSpec((lqp,), lambda i: (0,)),                # q mask pinned
-            pl.BlockSpec((block_docs, t, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_docs,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block_docs,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((kp,), jnp.float32),
-        interpret=interpret,
-    )(q, q_mask, docs_packed, doc_lens)
-    return out[:k]
+    Returns (K,) fp32 asymmetric MaxSim scores. ``interpret=None`` follows
+    the backend in use (``repro.kernels.resolve_interpret``)."""
+    k, _, w = docs_packed.shape
+    q = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, w * 32 - d)))
+    words = jax.lax.bitcast_convert_type(docs_packed.astype(jnp.uint32),
+                                         jnp.int32)
+    q, q_mask, words, doc_lens, tp, kp = pad_operands(q, q_mask, words,
+                                                      doc_lens, block_docs)
+    out = tiled_call(_kernel, q, q_mask, words, doc_lens, tp=tp, kp=kp,
+                     block_docs=block_docs, interpret=interpret)
+    return out.reshape(kp)[:k]

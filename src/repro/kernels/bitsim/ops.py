@@ -16,11 +16,12 @@ def _ref_jit(q, q_mask, docs_packed, doc_lens, d):
 
 
 def bitsim(q, q_mask, docs_packed, doc_lens, *, d: int,
-           use_pallas: bool = False, interpret: bool = True,
+           use_pallas: bool = False, interpret: bool | None = None,
            block_docs: int = 16):
     """Asymmetric MaxSim scores (K,) fp32: full-precision query tokens vs
     sign-packed uint32 document lanes. use_pallas=True -> TPU kernel
-    (interpret=True executes the kernel body on CPU for validation)."""
+    (interpret=None follows the backend: interpreted on CPU,
+    compiled on TPU)."""
     if use_pallas:
         return bitsim_pallas(q, q_mask, docs_packed, doc_lens, d=d,
                              block_docs=block_docs, interpret=interpret)

@@ -5,12 +5,14 @@ matmul against the resident FDE table (brute force under the IVF
 threshold). The kernel tiles the document axis: the query FDE block is
 pinned in VMEM across the whole grid (block-0 index_map, same trick as
 maxsim/bitsim) while (BN, D) document tiles stream through, each step
-running ONE MXU matmul and writing a (B, BN) score tile. The fp16 table
-tile is upcast in registers, so HBM traffic stays at 2 bytes/element.
+running ONE MXU matmul and writing a (B, BN) score tile. Mosaic has no
+fp16 vector loads on a v5e, so the table's pad + upcast to fp32 is an XLA
+op that ``allow_input_fusion`` fuses into the kernel's operand: the fp16
+table is read from HBM at 2 bytes/element and no fp32 copy is materialized.
 
-VMEM budget per step (defaults BN=256, D=128): doc tile 256*128*2 = 64 KB
-+ q block — far under the 16 MB ceiling. Alignment: D padded to a lane
-multiple of 128, B to the fp32 sublane 8, BN a multiple of 128.
+VMEM budget per step (defaults BN=256, D=256): fp32 doc tile 256*256*4 =
+256 KB + q block — far under the 16 MB ceiling. Alignment: D padded to a
+lane multiple of 128, B to the fp32 sublane 8, BN a multiple of 128.
 """
 from __future__ import annotations
 
@@ -19,29 +21,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(q_ref, d_ref, out_ref):
-    q = q_ref[...].astype(jnp.float32)               # (Bp, Dp)
-    d = d_ref[...].astype(jnp.float32)               # (BN, Dp)
     out_ref[...] = jax.lax.dot_general(
-        q, d, (((1,), (1,)), ((), ())),
+        q_ref[...], d_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (Bp, BN)
 
 
 @functools.partial(jax.jit, static_argnames=("block_docs", "interpret"))
 def fdescan_pallas(q, docs, *, block_docs: int = 256,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """q: (B, D) float; docs: (N, D) float (any float dtype, e.g. the fp16
     resident table). Returns (B, N) fp32 scores. Pads B to 8, D to 128, and
-    N to block_docs; zero padding cannot perturb the inner products."""
+    N to block_docs; zero padding cannot perturb the inner products.
+    ``interpret=None`` follows the backend in use
+    (``repro.kernels.resolve_interpret``)."""
     b, d_dim = q.shape
     n = docs.shape[0]
     bp = -(-b // 8) * 8
     dp = -(-d_dim // 128) * 128
     np_ = -(-n // block_docs) * block_docs
-    q = jnp.pad(q, ((0, bp - b), (0, dp - d_dim)))
-    docs = jnp.pad(docs, ((0, np_ - n), (0, dp - d_dim)))
+    q = jnp.pad(q.astype(jnp.float32), ((0, bp - b), (0, dp - d_dim)))
+    docs = jnp.pad(docs.astype(jnp.float32), ((0, np_ - n), (0, dp - d_dim)))
 
     grid = (np_ // block_docs,)
     out = pl.pallas_call(
@@ -53,6 +58,8 @@ def fdescan_pallas(q, docs, *, block_docs: int = 256,
         ],
         out_specs=pl.BlockSpec((bp, block_docs), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), jnp.float32),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            allow_input_fusion=[False, True]),
+        interpret=resolve_interpret(interpret),
     )(q, docs)
     return out[:b, :n]

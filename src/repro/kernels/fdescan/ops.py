@@ -10,11 +10,11 @@ from repro.kernels.fdescan.ref import fdescan_ref
 _ref_jit = jax.jit(fdescan_ref)
 
 
-def fdescan(q, docs, *, use_pallas: bool = False, interpret: bool = True,
-            block_docs: int = 256):
+def fdescan(q, docs, *, use_pallas: bool = False,
+            interpret: bool | None = None, block_docs: int = 256):
     """Batched FDE scoring: q (B, D) x docs (N, D) -> (B, N) fp32 inner
-    products. use_pallas=True -> TPU kernel (interpret=True executes the
-    kernel body on CPU for validation)."""
+    products. use_pallas=True -> TPU kernel (interpret=None follows the
+    backend: interpreted on CPU, compiled on TPU)."""
     if use_pallas:
         return fdescan_pallas(q, docs, block_docs=block_docs,
                               interpret=interpret)

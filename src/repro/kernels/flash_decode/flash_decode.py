@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG = -1e30
 
 
@@ -60,7 +62,7 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, out_ref, m_ref, l_ref, acc_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def flash_decode_pallas(q, k_cache, v_cache, lengths, *, chunk: int = 512,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q: (B, KV, G, Dh); k_cache/v_cache: (B, S, KV, Dh);
     lengths: (B,) int32 valid cache length per sequence.
     Returns (B, KV, G, Dh) attention output in q.dtype.
@@ -92,6 +94,6 @@ def flash_decode_pallas(q, k_cache, v_cache, lengths, *, chunk: int = 512,
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k_cache, v_cache, lengths.astype(jnp.int32))
     return out

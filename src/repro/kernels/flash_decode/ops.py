@@ -13,7 +13,7 @@ def _ref_jit(q, k_cache, v_cache, lengths):
 
 
 def flash_decode(q, k_cache, v_cache, lengths, *, use_pallas: bool = False,
-                 interpret: bool = True, chunk: int = 512):
+                 interpret: bool | None = None, chunk: int = 512):
     if use_pallas:
         return flash_decode_pallas(q, k_cache, v_cache, lengths,
                                    chunk=chunk, interpret=interpret)

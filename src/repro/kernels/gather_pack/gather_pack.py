@@ -20,22 +20,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(idx_ref, pool_ref, out_ref, *, t: int):
     idx = idx_ref[...]                                # (1, T)
 
     def body(j, _):
         row = jnp.maximum(idx[0, j], 0)
-        vec = pl.load(pool_ref, (pl.dslice(row, 1), slice(None)))   # (1, D)
+        vec = pool_ref[pl.ds(row, 1), :]                             # (1, D)
         valid = (idx[0, j] >= 0).astype(vec.dtype)
-        pl.store(out_ref, (pl.dslice(j, 1), slice(None)), vec * valid)
+        out_ref[pl.ds(j, 1), :] = vec * valid
         return 0
 
     jax.lax.fori_loop(0, t, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_pack_pallas(pool, idx, *, interpret: bool = True):
+def gather_pack_pallas(pool, idx, *, interpret: bool | None = None):
     """pool: (R, D) token rows; idx: (K, T) int32 row ids (-1 pad).
 
     Returns (K, T, D) padded doc tiles (pad rows zeroed).
@@ -51,6 +53,6 @@ def gather_pack_pallas(pool, idx, *, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1 * t, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((k * t, d), pool.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx, pool)
     return out.reshape(k, t, d)

@@ -12,7 +12,8 @@ def _ref_jit(pool, idx):
     return gather_pack_ref(pool, idx)
 
 
-def gather_pack(pool, idx, *, use_pallas: bool = False, interpret: bool = True):
+def gather_pack(pool, idx, *, use_pallas: bool = False,
+                interpret: bool | None = None):
     if use_pallas:
         return gather_pack_pallas(pool, idx, interpret=interpret)
     return _ref_jit(pool, idx)

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG = -1e30
 
 
@@ -32,7 +34,7 @@ def _kernel(q_ref, c_ref, nvalid_ref, out_ref, *, bn: int):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def ivf_scan_pallas(q, centroids, *, block_n: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (B, D); centroids: (N, D). Returns (B, N) fp32 scores
     (padded tail columns = -1e30 so downstream top-k ignores them)."""
     b, d = q.shape
@@ -53,6 +55,6 @@ def ivf_scan_pallas(q, centroids, *, block_n: int = 128,
         ],
         out_specs=pl.BlockSpec((bp, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, cp, nvalid)
     return out[:b, :n]

@@ -13,7 +13,7 @@ def _ref_jit(q, centroids):
 
 
 def centroid_scores(q, centroids, *, use_pallas: bool = False,
-                    interpret: bool = True, block_n: int = 128):
+                    interpret: bool | None = None, block_n: int = 128):
     if use_pallas:
         return ivf_scan_pallas(q, centroids, block_n=block_n,
                                interpret=interpret)

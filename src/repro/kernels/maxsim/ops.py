@@ -14,9 +14,10 @@ def _ref_jit(q, q_mask, docs, doc_lens):
 
 
 def maxsim(q, q_mask, docs, doc_lens, *, use_pallas: bool = False,
-           interpret: bool = True, block_docs: int = 16):
+           interpret: bool | None = None, block_docs: int = 16):
     """MaxSim scores (K,) fp32. use_pallas=True -> TPU kernel
-    (interpret=True executes the kernel body on CPU for validation)."""
+    (interpret=None follows the backend: interpreted on CPU,
+    compiled on TPU)."""
     if use_pallas:
         return maxsim_pallas(q, q_mask, docs, doc_lens,
                              block_docs=block_docs, interpret=interpret)

@@ -24,8 +24,11 @@ def main():
         cfg.corpus.n_clusters = max(64, cfg.index.resolve_ncells(
             cfg.corpus.n_docs) // 2)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.core.metrics import mrr_at_k, recall_at_k
     from repro.pipeline import Pipeline
+
+    enable_compile_cache()
 
     print(f"building corpus ({cfg.corpus.n_docs} docs) ...", flush=True)
     pipe = Pipeline.build(cfg)

@@ -23,7 +23,10 @@ def main(argv: list[str] | None = None) -> None:
     args = ap.parse_args(argv)
     cfg = PipelineConfig.from_cli(args)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.pipeline import Pipeline
+
+    enable_compile_cache()
 
     with Pipeline.build(cfg) as pipe:
         print(f"corpus: {pipe.corpus.n_docs} docs, "

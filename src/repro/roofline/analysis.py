@@ -18,10 +18,24 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-# TPU v5e-class hardware constants (per the brief)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link (~per-direction)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect — taken
+# here as 4 ICI links of 50 GB/s). jax names a v5e "TPU v5 lite".
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes": 16e9,
+                    "hbm_bw": 819e9, "ici_links": 4, "ici_link_bw": 50e9},
+}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; a kind without published
+    peaks in ``DEVICE_PEAKS`` is an error, never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(DEVICE_PEAKS)}") from None
 
 _DTYPE_BYTES = {"pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1, "s16": 2,
                 "u16": 2, "f16": 2, "bf16": 2, "s32": 4, "u32": 4, "f32": 4,
@@ -132,10 +146,6 @@ class Roofline:
 def extract_raw(compiled) -> dict:
     """Per-device (flops, bytes, wire bytes, per-kind breakdown)."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        # older jax returns one properties dict per program; sum the totals
-        ca = {k: sum(float(prog.get(k, 0.0)) for prog in ca)
-              for k in ("flops", "bytes accessed")}
     coll = parse_collectives(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),
@@ -176,10 +186,12 @@ def memory_gb(compiled) -> float:
 
 def roofline_from_raw(raw: dict, *, arch: str, shape: str, mesh_name: str,
                       n_dev: int, model_flops: float, mem_gb: float,
-                      links: int = 4) -> Roofline:
-    compute_s = raw["flops"] / PEAK_FLOPS
-    memory_s = raw["bytes"] / HBM_BW
-    collective_s = raw["wire_bytes"] / (links * LINK_BW)
+                      device_kind: str) -> Roofline:
+    peaks = device_peaks(device_kind)
+    compute_s = raw["flops"] / peaks["bf16_flops"]
+    memory_s = raw["bytes"] / peaks["hbm_bw"]
+    collective_s = raw["wire_bytes"] / (peaks["ici_links"]
+                                        * peaks["ici_link_bw"])
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
@@ -195,10 +207,3 @@ def roofline_from_raw(raw: dict, *, arch: str, shape: str, mesh_name: str,
                                  for k, v in raw["by_kind"].items()},
                     counts=raw["counts"])
 
-
-def analyze(compiled, *, arch: str, shape: str, mesh_name: str, n_dev: int,
-            model_flops: float, links: int = 4) -> Roofline:
-    raw = extract_raw(compiled)
-    return roofline_from_raw(raw, arch=arch, shape=shape, mesh_name=mesh_name,
-                             n_dev=n_dev, model_flops=model_flops,
-                             mem_gb=memory_gb(compiled), links=links)
