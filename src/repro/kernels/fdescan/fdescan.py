@@ -7,8 +7,11 @@ pinned in VMEM across the whole grid (block-0 index_map, same trick as
 maxsim/bitsim) while (BN, D) document tiles stream through, each step
 running ONE MXU matmul and writing a (B, BN) score tile. Mosaic has no
 fp16 vector loads on a v5e, so the table's pad + upcast to fp32 is an XLA
-op that ``allow_input_fusion`` fuses into the kernel's operand: the fp16
-table is read from HBM at 2 bytes/element and no fp32 copy is materialized.
+op that ``allow_input_fusion`` asks XLA to fuse into the kernel's operand.
+The program compiled for a v5e does fuse it: its entry computation takes
+the fp16 table straight into the kernel and holds no fp32 copy
+(``tests/test_tpu_compile.py`` checks the HLO). The bytes read per step on
+the chip have not been measured.
 
 VMEM budget per step (defaults BN=256, D=256): fp32 doc tile 256*256*4 =
 256 KB + q block — far under the 16 MB ceiling. Alignment: D padded to a

@@ -2,10 +2,8 @@
 backend, the per-device peak table, the compile-cache location, and the chip
 smoke script's refusal to run without a TPU (plus its phases at a tiny size
 on the CPU)."""
-import argparse
 import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -88,35 +86,69 @@ def test_compile_cache_fixed_repo_dir(monkeypatch, cache_dir_restored):
     assert enable_compile_cache() == path          # stable across calls
 
 
-def test_chip_smoke_refuses_cpu():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
-                       cwd=REPO, env=env, capture_output=True, text=True,
-                       timeout=300)
-    assert r.returncode != 0
-    assert '"ok"' not in r.stdout
-    assert "needs a TPU" in r.stderr
-
-
-def test_chip_smoke_phases_tiny_cpu(capsys):
-    """Every phase of the smoke (build, four served windows checked against
-    the host reference, Pallas vs XLA, encoder) at a tiny size."""
+@pytest.fixture(scope="module")
+def chip_smoke():
     sys.path.insert(0, REPO)
     try:
         import chip_smoke
     finally:
         sys.path.remove(REPO)
+    return chip_smoke
+
+
+def test_chip_smoke_refuses_cpu(chip_smoke, capsys):
+    assert jax.devices()[0].platform == "cpu"
+    assert chip_smoke.main([]) == 2
+    out, err = capsys.readouterr()
+    assert '"ok"' not in out
+    assert "needs a TPU" in err
+
+
+TINY = dict(docs=1500, queries=6, k=60, nprobe=8, encode_batch=2,
+            fde_docs=700, fde_batch=3, timeout_s=300.0)
+
+
+def test_chip_smoke_phases_tiny_cpu(chip_smoke, capsys):
+    """Every phase of the smoke (build, four served windows checked against
+    the host oracles, Pallas vs XLA, the bitsim/fdescan kernel checks,
+    encoder) at a tiny size."""
     from repro.configs.base import get_config
     from repro.models.colberter import smoke_config
-    args = argparse.Namespace(docs=1500, queries=6, k=60, nprobe=8,
-                              encode_batch=2, seed=0, timeout=300.0)
-    failures = chip_smoke.run(args, "cpu",
+    failures = chip_smoke.run(chip_smoke.Sizes(**TINY), "cpu",
                               encoder_cfg=smoke_config(get_config(
                                   "colberter")))
     assert failures == []
     out = capsys.readouterr().out
     for phase in ("xla/cold", "xla/warm", "pallas/cold", "pallas/warm"):
         assert f"serve {phase}: 6 queries, errors 0 shed 0 timeouts 0" in out
+    assert out.count("candidates failing the IVF oracle 0,") == 4
+    assert "pallas vs xla: same candidate sets: True" in out
+    assert "bitsim K 60 x T 180 x D 32" in out
     assert "modelled (ComputeModel + SSD clock, not measured)" in out
     assert not any(line.startswith("{") and json.loads(line).get("ok")
                    for line in out.splitlines())
+
+
+def test_host_ivf_oracle_refuses_wrong_candidates(chip_smoke):
+    """The candidate check admits the plain float32 top-k and refuses a
+    set with a certain top-k document swapped for one of an unprobed cell,
+    a duplicate, or a short list."""
+    import numpy as np
+
+    from repro.core.ivf import build_ivf
+    r = np.random.default_rng(0)
+    cls = r.standard_normal((3000, 16)).astype(np.float32)
+    cls /= np.linalg.norm(cls, axis=1, keepdims=True)
+    idx = build_ivf(cls, 30, seed=0)
+    ivf = chip_smoke.HostIVF(idx, cls, nprobe=4, k=50)
+    q = cls[7] + 0.1 * r.standard_normal(16).astype(np.float32)
+    q /= np.linalg.norm(q)
+    exp = ivf.expect(q)
+    assert ivf.check(exp, exp["ref"]) == []
+    assert ivf.check(exp, exp["ref"][::-1]) == []
+    far = np.setdiff1d(np.arange(3000), exp["may"])[:1]
+    swapped = np.concatenate([exp["ref"][1:], far])
+    assert len(ivf.check(exp, swapped)) == 2        # one missing, one extra
+    assert ivf.check(exp, np.concatenate([exp["ref"][:-1],
+                                          exp["ref"][:1]]))
+    assert ivf.check(exp, exp["ref"][:-5])

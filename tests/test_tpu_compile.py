@@ -80,6 +80,21 @@ def test_kernel_compiles_for_v5e(chip, name, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_fdescan_fp16_upcast_fused_into_kernel(chip):
+    """The fp16 table's pad + fp32 upcast is fused into the kernel operand:
+    the entry computation holds no fp32 copy of the table and the program
+    needs no temporary buffer of that size."""
+    compiled = _lowered("fdescan", chip, jnp.float16).compile()
+    rows = -(-FDE_DOCS // 256) * 256
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    assert "tpu_custom_call" in text
+    assert f"f32[{rows},{FDE_DIM}]" not in entry
+    assert f"f16[{FDE_DOCS},{FDE_DIM}]" in entry
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * FDE_DIM * 4
+
+
 def test_ivf_probe_cells_compiles_for_v5e(chip, hbm_bytes):
     from repro.core.ivf import probe_cells
     compiled = probe_cells.lower(_sds(chip, (NCELLS, D_CLS), jnp.float32),
