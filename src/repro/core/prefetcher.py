@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core.ivf import (ANNCostModel, IVFIndex, mask_dead,
                             search_two_phase, valid_candidates)
+from repro.obs.trace import stage
 from repro.storage.io_engine import StorageTier
 
 
@@ -119,9 +120,11 @@ class ANNPrefetcher:
         per-query blocking reads bit for bit.
         """
         delta = self.delta(nprobe)
-        approx, final, _ = search_two_phase(self.index, q, nprobe, k, delta)
-        a_scores, a_ids = map(np.asarray, approx)
-        f_scores, f_ids = map(np.asarray, final)
+        with stage("ivf_search"):
+            approx, final, _ = search_two_phase(self.index, q, nprobe, k,
+                                                delta)
+            a_scores, a_ids = map(np.asarray, approx)
+            f_scores, f_ids = map(np.asarray, final)
         # tombstones: deleted docs become -1 padding BEFORE the prefetch and
         # miss lists form, so they are never fetched, never scored, and never
         # inserted into any cache

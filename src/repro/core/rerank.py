@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.maxsim import maxsim_scores
+from repro.obs.trace import stage
 from repro.storage.faults import DegradedQueryError
 
 
@@ -39,15 +40,21 @@ def _maxsim_np(q_bow: np.ndarray, q_len: int, d_bow: np.ndarray,
         return np.zeros((0,), np.float32)
     if use_pallas:
         from repro.kernels.maxsim.ops import maxsim as maxsim_kernel
-        return np.asarray(maxsim_kernel(
-            jnp.asarray(q_bow[:q_len]), jnp.ones((q_len,), jnp.float32),
-            jnp.asarray(d_bow), jnp.asarray(d_lens), use_pallas=True))
-    q = jnp.asarray(q_bow[None, :q_len])
-    qm = jnp.ones((1, q_len), bool)
-    d = jnp.asarray(d_bow[None])
-    dm = (jnp.arange(d_bow.shape[1])[None, None, :]
-          < jnp.asarray(d_lens)[None, :, None])
-    return np.asarray(maxsim_scores(q, qm, d, dm)[0])
+        with stage("h2d"):
+            q, d, lens = (jnp.asarray(q_bow[:q_len]), jnp.asarray(d_bow),
+                          jnp.asarray(d_lens))
+        with stage("maxsim"):
+            return np.asarray(maxsim_kernel(
+                q, jnp.ones((q_len,), jnp.float32), d, lens,
+                use_pallas=True))
+    with stage("h2d"):
+        q, d, lens = (jnp.asarray(q_bow[None, :q_len]),
+                      jnp.asarray(d_bow[None]), jnp.asarray(d_lens))
+    with stage("maxsim"):
+        qm = jnp.ones((1, q_len), bool)
+        dm = (jnp.arange(d_bow.shape[1])[None, None, :]
+              < lens[None, :, None])
+        return np.asarray(maxsim_scores(q, qm, d, dm)[0])
 
 
 def degraded_rerank(result, *, alpha: float = 1.0,
@@ -99,7 +106,8 @@ def rerank_query(q_bow, q_len, result, *, alpha: float = 1.0,
     if result.wait_io is not None:
         # batch I/O engine: block until this query's arena runs have landed
         # (reads of later queries keep streaming while we score this one)
-        result.wait_io()
+        with stage("io_wait"):
+            result.wait_io()
     ids = result.doc_ids
     k = len(ids)
     if select is not None:
@@ -112,34 +120,34 @@ def rerank_query(q_bow, q_len, result, *, alpha: float = 1.0,
 
     bow_scores = np.zeros(k, np.float32)
     bytes_read = 0
-    # hits: scored from the prefetch buffers (early re-rank)
-    pref_rows, pref_pos = [], []
-    miss_rows, miss_pos = [], []
-    miss_row_of = {}
-    if result.miss_rows is not None:
-        # batch I/O engine: rows point into the shared miss arena directly
-        miss_row_of = result.miss_rows
-    elif result.miss_buffers is not None:
-        miss_ids = ids[~result.hit_mask]
-        miss_row_of = {int(i): j for j, i in enumerate(miss_ids)}
-    for j in sel:
-        i = int(ids[j])
-        if i in result.prefetched and result.buffers is not None:
-            pref_rows.append(result.prefetched[i])
-            pref_pos.append(j)
-        elif i in miss_row_of:
-            miss_rows.append(miss_row_of[i])
-            miss_pos.append(j)
-    if pref_rows:
-        _, bow, lens = result.buffers
-        s = _maxsim_np(q_bow, q_len, bow[pref_rows], lens[pref_rows],
-                       use_pallas)
-        bow_scores[pref_pos] = s
-    if miss_rows:
-        _, bow, lens = result.miss_buffers
-        s = _maxsim_np(q_bow, q_len, bow[miss_rows], lens[miss_rows],
-                       use_pallas)
-        bow_scores[miss_pos] = s
+    with stage("rerank_gather"):
+        # hits: scored from the prefetch buffers (early re-rank)
+        pref_rows, pref_pos = [], []
+        miss_rows, miss_pos = [], []
+        miss_row_of = {}
+        if result.miss_rows is not None:
+            # batch I/O engine: rows point into the shared miss arena
+            miss_row_of = result.miss_rows
+        elif result.miss_buffers is not None:
+            miss_ids = ids[~result.hit_mask]
+            miss_row_of = {int(i): j for j, i in enumerate(miss_ids)}
+        for j in sel:
+            i = int(ids[j])
+            if i in result.prefetched and result.buffers is not None:
+                pref_rows.append(result.prefetched[i])
+                pref_pos.append(j)
+            elif i in miss_row_of:
+                miss_rows.append(miss_row_of[i])
+                miss_pos.append(j)
+        parts = []                     # (positions, token rows, lengths)
+        if pref_rows:
+            _, bow, lens = result.buffers
+            parts.append((pref_pos, bow[pref_rows], lens[pref_rows]))
+        if miss_rows:
+            _, bow, lens = result.miss_buffers
+            parts.append((miss_pos, bow[miss_rows], lens[miss_rows]))
+    for pos, bow, lens in parts:
+        bow_scores[pos] = _maxsim_np(q_bow, q_len, bow, lens, use_pallas)
     if doc_bytes is not None:
         bytes_read = int(sum(doc_bytes(int(ids[j])) for j in sel))
 

@@ -6,7 +6,8 @@ bitwise-identical to a build without this package on the path):
 
 * ``repro.obs.trace`` — a dual-clock (wall + simulated device) ``Tracer``
   whose spans are stitched into one tree per query and exported as
-  Chrome/Perfetto trace-event JSON.
+  Chrome/Perfetto trace-event JSON; its ``stage`` also writes the served
+  path's ``espn.*`` spans into a running JAX profiler session.
 * ``repro.obs.metrics`` — constant-memory counters/gauges/log-bucketed
   streaming histograms plus a ``MetricsRegistry`` with Prometheus-style
   text exposition.

@@ -19,6 +19,12 @@ instruction stream (the bitwise-identity invariant).
 ``export()`` writes the Chrome trace-event JSON Perfetto loads directly:
 wall spans on pid 1, and a parallel "device clock" track on pid 2 carrying
 one event per span with nonzero ``sim_s`` (duration = simulated seconds).
+
+``stage`` is the one span primitive of the served path. It always opens a
+``jax.profiler.TraceAnnotation`` named ``espn.<name>`` (a flag check when no
+profiler session runs; inside one, the span lands on the host plane of the
+same trace as the device's programs, on the same clock), and, given a
+``Tracer``, also records the wall span there.
 """
 from __future__ import annotations
 
@@ -26,6 +32,10 @@ import json
 import threading
 import time
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
+
+ANNOTATION_PREFIX = "espn."
 
 
 @dataclass
@@ -63,6 +73,7 @@ class Tracer:
         self.clock = clock
         self._lock = threading.Lock()
         self._spans: list[Span] = []
+        self._by_qid: dict = {}        # qid -> its spans, for query_sims
         self._next_sid = 0
         self._open = 0
         self._local = threading.local()
@@ -88,6 +99,7 @@ class Tracer:
             span.sid = self._next_sid
             self._next_sid += 1
             self._spans.append(span)
+            self._by_qid.setdefault(span.qid, []).append(span)
             if open_:
                 self._open += 1
         return span
@@ -174,15 +186,18 @@ class Tracer:
 
     def query_sims(self, qid, names=None) -> dict[str, float]:
         """Sum ``sim_s`` per span name over spans tagged with ``qid``."""
+        with self._lock:
+            spans = list(self._by_qid.get(qid, ()))
         out: dict[str, float] = {}
-        for sp in self.spans():
-            if sp.qid == qid and (names is None or sp.name in names):
+        for sp in spans:
+            if names is None or sp.name in names:
                 out[sp.name] = out.get(sp.name, 0.0) + sp.sim_s
         return out
 
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self._by_qid.clear()
             self._open = 0
 
     # -- export --------------------------------------------------------------
@@ -241,3 +256,37 @@ class _SpanCtx:
     def __exit__(self, *exc) -> None:
         if self.span is not None and self.span.t1 is None:
             self.tr.end(self.span)
+
+
+class stage:
+    """``with stage(name, tracer):`` times one stage of the served path.
+
+    Enters ``TraceAnnotation("espn." + name)`` always; when ``tracer`` is
+    given, also opens the wall span ``name`` (category ``cat``) in it and
+    yields it, else yields None. A caller may end the span itself inside
+    the block (``tracer.end(span, sim_s=...)``); otherwise it ends on exit,
+    with ``error=True`` when the block raised. Adds no synchronisation.
+    """
+
+    __slots__ = ("_ann", "_tr", "_name", "_cat", "_span")
+
+    def __init__(self, name: str, tracer: Tracer | None = None,
+                 cat: str = ""):
+        self._ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+        self._tr, self._name, self._cat = tracer, name, cat
+        self._span = None
+
+    def __enter__(self) -> Span | None:
+        self._ann.__enter__()
+        if self._tr is not None:
+            self._span = self._tr.begin(self._name, self._cat)
+        return self._span
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        sp = self._span
+        if sp is not None and sp.t1 is None:
+            if exc_type is None:
+                self._tr.end(sp)
+            else:
+                self._tr.end(sp, error=True)
+        self._ann.__exit__(exc_type, exc, tb)

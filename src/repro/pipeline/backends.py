@@ -27,6 +27,7 @@ from repro.core.ivf import (ANNCostModel, IVFIndex, build_ivf, ivf_add,
                             mask_dead, search, valid_candidates)
 from repro.core.prefetcher import ANNPrefetcher, QueryResult
 from repro.core.rerank import RerankOutput, rerank_query
+from repro.obs.trace import stage
 from repro.storage.batch_io import consumption_dedup_saved
 from repro.storage.io_engine import StorageTier
 
@@ -94,36 +95,33 @@ class RetrievalBackend(abc.ABC):
     def query_batch(self, q_cls: np.ndarray, q_bow: np.ndarray,
                     q_lens: np.ndarray) -> RetrievalResponse:
         tr = self.tracer
-        root = None
         if tr is not None:
             tr.adopt_batch_qids()
-            root = tr.begin("query_batch", cat="batch", mode=self.name,
-                            n_queries=int(q_cls.shape[0]))
-        bd = LatencyBreakdown()
-        bd.encode_s = self.compute.encode_time(q_cls.shape[0])
-        if tr is not None:
-            tr.add("encode", cat="compute", sim_s=bd.encode_s)
-        # hedged re-issues and injected faults happen inside the tier
-        # (storage cluster); surface this batch's share as stats deltas
-        # without any per-backend plumbing
-        _FKEYS = ("retries", "checksum_failures", "repair_bytes",
-                  "faults_injected")
-        hedge0 = self.tier.stats.get("hedge_bytes", 0)
-        f0 = {k: self.tier.stats.get(k, 0) for k in _FKEYS}
-        try:
+        with stage("query_batch", tr, "batch") as root:
+            if root is not None:
+                root.args.update(mode=self.name,
+                                 n_queries=int(q_cls.shape[0]))
+            bd = LatencyBreakdown()
+            bd.encode_s = self.compute.encode_time(q_cls.shape[0])
+            if tr is not None:
+                tr.add("encode", cat="compute", sim_s=bd.encode_s)
+            # hedged re-issues and injected faults happen inside the tier
+            # (storage cluster); surface this batch's share as stats deltas
+            # without any per-backend plumbing
+            _FKEYS = ("retries", "checksum_failures", "repair_bytes",
+                      "faults_injected")
+            hedge0 = self.tier.stats.get("hedge_bytes", 0)
+            f0 = {k: self.tier.stats.get(k, 0) for k in _FKEYS}
             ranked = self._retrieve(q_cls, q_bow, q_lens, bd)
-        except BaseException:
-            if root is not None and not root.closed:
-                tr.end(root, error=True)
-            raise
-        bd.hedge_bytes_read = self.tier.stats.get("hedge_bytes", 0) - hedge0
-        for k in _FKEYS:
-            setattr(bd, k, self.tier.stats.get(k, 0) - f0[k])
-        bd.degraded_queries = sum(int(r.degraded) for r in ranked)
-        bd.total_s = (bd.encode_s + bd.ann_s + bd.critical_io_s + bd.rerank_s
-                      + 0.2e-3)
-        if tr is not None:
-            tr.end(root, sim_s=bd.total_s, breakdown=bd.as_dict())
+            bd.hedge_bytes_read = (self.tier.stats.get("hedge_bytes", 0)
+                                   - hedge0)
+            for k in _FKEYS:
+                setattr(bd, k, self.tier.stats.get(k, 0) - f0[k])
+            bd.degraded_queries = sum(int(r.degraded) for r in ranked)
+            bd.total_s = (bd.encode_s + bd.ann_s + bd.critical_io_s
+                          + bd.rerank_s + 0.2e-3)
+            if root is not None:
+                tr.end(root, sim_s=bd.total_s, breakdown=bd.as_dict())
         return RetrievalResponse(ranked=ranked, breakdown=bd)
 
     @abc.abstractmethod
@@ -309,13 +307,12 @@ class ESPNBackend(RetrievalBackend):
         tr = self.tracer
         if q_cls.shape[0] == 0:           # empty batch: nothing to rank,
             return []                     # hit_rate keeps its vacuous default
-        cspan = tr.begin("candidate_gen", cat="compute") \
-            if tr is not None else None
-        results = self.prefetcher.run_batch(q_cls, nprobe=cfg.nprobe,
-                                            k=cfg.k_candidates)
-        bd.ann_s = results[0].stats.ann_s
-        if tr is not None:
-            tr.end(cspan, sim_s=bd.ann_s)
+        with stage("candidate_gen", tr, "compute") as cspan:
+            results = self.prefetcher.run_batch(q_cls, nprobe=cfg.nprobe,
+                                                k=cfg.k_candidates)
+            bd.ann_s = results[0].stats.ann_s
+            if cspan is not None:
+                tr.end(cspan, sim_s=bd.ann_s)
         ranked, hit_rates, hidden, critical = [], [], 0.0, 0.0
         for b, res in enumerate(results):
             out = rerank_query(q_bow[b], int(q_lens[b]), res,

@@ -18,6 +18,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro.core.fde import FDETable
+from repro.obs.trace import stage
 from repro.storage import ssd as ssd_lib
 from repro.storage.batch_io import (BatchReadPlan, BatchReadResult,
                                     _exclusive_cumsum, serial_batch)
@@ -236,6 +237,12 @@ class StorageTier:
             tr.end(sp, sim_s=res.sim_seconds)
             res.span = sp
             return res
+        with stage("read_submit"):
+            return self._submit_coalesced(lists, t_max)
+
+    def _submit_coalesced(self, lists: list, t_max: int) -> BatchReadResult:
+        """``read_batch``'s coalesced path: plan, arena, run submission."""
+        tr = self.tracer
         t_plan0 = tr.clock() if tr is not None else 0.0
         plan = BatchReadPlan.build(self.layout, lists,
                                    chunk_docs=self.io_chunk_docs)

@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core.quantize import binary_pack, to_uint32_lanes
+from repro.obs.trace import stage
 from repro.storage.ssd import DEFAULT_BLOCK
 
 LAYOUT_MODES = ("ragged", "fixed_stride")
@@ -381,9 +382,10 @@ def gather_docs_into(layout: EmbeddingLayout, ids, out_cls: np.ndarray,
     batch and hands each block-contiguous run a disjoint slice, so runs can
     gather concurrently on the tier's thread pool with no further copies.
     """
-    ids = np.asarray(ids, np.int64)
-    gather_docs_at(layout, ids, np.arange(len(ids)), out_cls, out_bow,
-                   out_lens)
+    with stage("read_run"):
+        ids = np.asarray(ids, np.int64)
+        gather_docs_at(layout, ids, np.arange(len(ids)), out_cls, out_bow,
+                       out_lens)
 
 
 def gather_docs(layout: EmbeddingLayout, ids, t_max: int):

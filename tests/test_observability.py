@@ -254,6 +254,102 @@ def test_tracing_is_bitwise_invisible(faulted, mode, small_corpus):
     on.close()
 
 
+def _profiled(fn, logdir):
+    """Run ``fn`` under a profiler session; the host plane's lines (one per
+    thread), each a list of (name, start_ns, end_ns)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)[0]
+    plane = next(p for p in ProfileData.from_file(path).planes
+                 if p.name == "/host:CPU")
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in ln.events] for ln in plane.lines]
+    return out, lines
+
+
+SERVED_SPANS = {"espn.query_batch", "espn.candidate_gen", "espn.ivf_search",
+                "espn.read_submit", "espn.read_run", "espn.io_wait",
+                "espn.rerank_gather", "espn.h2d", "espn.maxsim"}
+
+
+def test_profiler_spans_on_the_served_path(plain, small_corpus, tmp_path):
+    c = small_corpus
+    pipe = plain.with_mode("espn")
+
+    def run():
+        return pipe.backend.query_batch(c.queries_cls, c.queries_bow,
+                                        c.query_lens)
+    r_off = run()
+    r_on, lines = _profiled(run, str(tmp_path))
+    pipe.close()
+    for a, b in zip(r_off.ranked, r_on.ranked):
+        assert np.array_equal(a.doc_ids, b.doc_ids)
+        assert np.array_equal(a.scores, b.scores)
+    names = {n for ln in lines for n, _, _ in ln}
+    assert SERVED_SPANS <= names, SERVED_SPANS - names
+    batcher = [ln for ln in lines
+               if any(n == "espn.query_batch" for n, _, _ in ln)]
+    assert len(batcher) == 1
+    (qb,) = [(s, e) for n, s, e in batcher[0] if n == "espn.query_batch"]
+    inner = [(n, s, e) for n, s, e in batcher[0]
+             if n in ("espn.io_wait", "espn.h2d", "espn.maxsim")]
+    assert {n for n, _, _ in inner} == {"espn.io_wait", "espn.h2d",
+                                        "espn.maxsim"}
+    assert all(qb[0] <= s <= e <= qb[1] for _, s, e in inner)
+    # the tier's pool gathers the runs, off the batcher's thread
+    assert any(n == "espn.read_run" for ln in lines if ln is not batcher[0]
+               for n, _, _ in ln)
+
+
+def test_stage_feeds_the_tracer():
+    from repro.obs.trace import stage
+    with stage("x") as sp:
+        assert sp is None                       # no tracer: profiler only
+    tr = Tracer()
+    with stage("outer", tr, "batch") as outer:
+        with stage("inner", tr) as inner:
+            tr.end(inner, sim_s=2.0)            # ended by the caller
+    with pytest.raises(ValueError):
+        with stage("failing", tr) as failing:
+            raise ValueError
+    assert tr.open_count() == 0
+    assert (outer.name, outer.cat, outer.closed) == ("outer", "batch", True)
+    assert inner.parent == outer.sid and inner.sim_s == 2.0
+    assert failing.args == {"error": True} and failing.closed
+
+
+def test_query_sims_index_matches_a_scan(faulted, small_corpus, tmp_path):
+    c = small_corpus
+    pipe = faulted.with_mode("espn")
+    srv = pipe.serve(trace_path=str(tmp_path / "serve.json"))
+    tr = srv.tracer
+    for i in range(10):
+        srv.query(c.queries_cls[i], c.queries_bow[i], int(c.query_lens[i]))
+    srv.shutdown()
+    pipe.close()
+    spans = tr.spans()
+    qids = {s.qid for s in spans}
+    assert len(qids - {None}) >= 10
+    for qid in qids:
+        for names in (None, ("critical_io", "rerank")):
+            scan = {}
+            for s in spans:
+                if s.qid == qid and (names is None or s.name in names):
+                    scan[s.name] = scan.get(s.name, 0.0) + s.sim_s
+            assert tr.query_sims(qid, names) == scan
+    tr.clear()
+    assert tr.query_sims(next(iter(qids - {None}))) == {}
+
+
 def test_trace_export_is_perfetto_loadable(faulted, small_corpus, tmp_path):
     pipe, tr, _ = _traced_run(faulted, "espn", small_corpus)
     path = str(tmp_path / "trace.json")
