@@ -27,6 +27,7 @@ the survivors.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -359,19 +360,48 @@ def gather_docs_at(layout: EmbeddingLayout, ids, rows, out_cls: np.ndarray,
     batch's shared arena (the arena is global-block-sorted while a shard owns
     a strided subset of it), so the contiguous-slice contract of
     ``gather_docs_into`` does not apply.
+
+    Ragged layouts take one bulk gather, a fixed number of numpy calls
+    whatever ``len(ids)``: every CLS vector and every kept token vector is
+    read by one fancy index each over the blob cut into units of ``ub``
+    bytes, converted to fp32 once, scaled once and scattered once. The
+    arena is bit-identical to ``unpack_doc`` per passage (same record
+    bytes, same conversion, then the same scale multiply); rows past a
+    passage's length are left untouched.
     """
     ids = np.asarray(ids, np.int64)
     rows = np.asarray(rows, np.int64)
-    if layout.mode == "fixed_stride" and len(ids):
+    if not len(ids):
+        return
+    if layout.mode == "fixed_stride":
         _gather_fixed_at(layout, ids, rows, out_cls, out_bow, out_lens)
         return
-    t_max = out_bow.shape[1]
-    for i, row in zip(ids, rows):
-        c, b = unpack_doc(layout, int(i))
-        t = min(b.shape[0], t_max)
-        out_bow[row, :t] = b[:t]
-        out_cls[row] = c
-        out_lens[row] = t
+    elt = layout.dtype.itemsize
+    cls_b, tok_b = layout.d_cls * elt, layout.d_bow * elt
+    # the widest unit of bytes every record, CLS vector and token vector
+    # starts and ends on, taken from the layout's widths: one whole token
+    # vector where the block and the CLS vector are multiples of it (4 KiB
+    # blocks, 128-d CLS, 32-d fp16 tokens), else a few items, one item, or
+    # one byte for a block that is no multiple of the item size
+    ub = math.gcd(layout.block, cls_b, tok_b)
+    units = layout.blob.reshape(-1, ub)
+    cls_u, tok_u = cls_b // ub, tok_b // ub
+    start = layout.offsets[ids, 0] * (layout.block // ub)
+    t = np.minimum(layout.n_tokens[ids], out_bow.shape[1]).astype(np.int64)
+    owner = np.repeat(np.arange(len(ids)), t)
+    pos = np.arange(len(owner)) - np.repeat(np.cumsum(t) - t, t)
+    tok0 = start[owner] + cls_u + pos * tok_u
+    cls = units[start[:, None] + np.arange(cls_u)] \
+        .reshape(len(ids), cls_b).view(layout.dtype).astype(np.float32)
+    bow = units[tok0[:, None] + np.arange(tok_u)] \
+        .reshape(len(owner), tok_b).view(layout.dtype).astype(np.float32)
+    if layout.scales is not None:
+        s = layout.scales[ids]
+        cls = cls * s[:, None]
+        bow = bow * s[owner][:, None]
+    out_bow[rows[owner], pos] = bow
+    out_cls[rows] = cls
+    out_lens[rows] = t
 
 
 def gather_docs_into(layout: EmbeddingLayout, ids, out_cls: np.ndarray,

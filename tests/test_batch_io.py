@@ -293,3 +293,35 @@ def test_empty_and_degenerate_batches():
     buffers, row_map, io_s = allempty.view(1)
     assert row_map == {} and io_s == 0.0
     tier.close()
+
+
+def test_coalesced_read_submits_gather_docs_into_itself():
+    """The fault-free coalesced path hands ``gather_docs_into`` itself to the
+    pool — one task per block-contiguous run — and the arena it fills holds
+    each passage exactly as ``unpack_doc`` reads it."""
+    from repro.storage.layout import gather_docs_into, unpack_doc
+    layout = _mini_layout()
+    tier = StorageTier(layout, stack="espn", t_max=32, io_chunk_docs=5)
+    submitted = []
+    orig_submit = tier._pool.submit
+
+    def recording_submit(fn, *a, **kw):
+        submitted.append(fn)
+        return orig_submit(fn, *a, **kw)
+
+    tier._pool.submit = recording_submit
+    try:
+        res = tier.read_batch([[3, 9, 10, 41], [9, 22, 57, 58, 0]],
+                              coalesce=True)
+        res.wait_all()
+        assert len(submitted) == len(res.plan.runs) > 1
+        assert all(fn is gather_docs_into for fn in submitted)
+        cls, bow, lens = res.arena
+        for row, i in enumerate(res.plan.arena_ids):
+            c, b = unpack_doc(layout, int(i))
+            t = min(b.shape[0], 32)
+            assert lens[row] == t
+            np.testing.assert_array_equal(cls[row], c)
+            np.testing.assert_array_equal(bow[row, :t], b[:t])
+    finally:
+        tier.close()

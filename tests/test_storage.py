@@ -149,3 +149,107 @@ def test_close_cancels_pending_async_reads():
     with pytest.raises(CancelledError):
         pending.result(timeout=10)
     assert running.result(timeout=10) is not None   # in-flight read finishes
+
+
+# -- bulk ragged gather: bit-for-bit against a per-passage unpack_doc loop --
+
+def _gather_layout(dtype=np.float16, d_cls=16, d_bow=8, n=40, seed=5,
+                   zero_doc=None, scaled=False):
+    rng = np.random.default_rng(seed)
+    cls = rng.standard_normal((n, d_cls)).astype(np.float32)
+    bow = [rng.standard_normal((int(t), d_bow)).astype(np.float32)
+           for t in rng.integers(1, 60, n)]
+    if zero_doc is not None:
+        bow[zero_doc] = np.zeros((0, d_bow), np.float32)
+    scales = None
+    if scaled:
+        scales = np.array([max(np.abs(c).max(), np.abs(b).max(initial=0.0))
+                           / 127.0 for c, b in zip(cls, bow)], np.float32)
+    return pack(cls, bow, dtype=dtype, scales=scales)
+
+
+def _unpack_reference(layout, ids, rows, out_cls, out_bow, out_lens):
+    for i, r in zip(ids, rows):
+        c, b = unpack_doc(layout, int(i))
+        t = min(b.shape[0], out_bow.shape[1])
+        out_bow[r, :t] = b[:t]
+        out_cls[r] = c
+        out_lens[r] = t
+
+
+def _arena(layout, n_rows, t_max, fill):
+    return (np.full((n_rows, layout.d_cls), fill, np.float32),
+            np.full((n_rows, t_max, layout.d_bow), fill, np.float32),
+            np.full(n_rows, -1, np.int32))
+
+
+def _bits(a):
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+# (layout kwargs, t_max, ids, rows: "contiguous" | "shuffled", arena fill)
+_GATHER_CASES = {
+    "float16": (dict(), 64, [3, 1, 4, 15, 9], "contiguous", 0.0),
+    "float32": (dict(dtype=np.float32), 64, [0, 7, 39, 2], "contiguous",
+                0.0),
+    "int8_scales": (dict(dtype=np.int8, scaled=True), 64,
+                    list(range(0, 40, 3)), "contiguous", 0.0),
+    "truncated_past_t_max": (dict(), 5, list(range(12)), "contiguous", 0.0),
+    "zero_token_passage": (dict(zero_doc=6), 64, [5, 6, 7], "contiguous",
+                           0.0),
+    "empty_ids": (dict(), 64, [], "contiguous", 0.0),
+    "shuffled_rows": (dict(), 64, [30, 2, 17, 8, 25, 11], "shuffled", 0.0),
+    "element_view_d_cls_not_multiple": (dict(d_cls=15), 64,
+                                        [1, 38, 20, 4], "contiguous", 0.0),
+    "sentinel_kept_past_length": (dict(zero_doc=2), 48, list(range(10)),
+                                  "shuffled", 7.25),
+}
+
+
+@pytest.mark.parametrize("case", list(_GATHER_CASES))
+def test_bulk_gather_bit_identical_to_unpack_doc(case):
+    """The ragged gather reads every passage in one bulk pass; the arena it
+    fills must equal, bit for bit, a per-passage ``unpack_doc`` loop —
+    across dtypes and scales, truncation, zero-token passages, empty input,
+    interleaved cluster-style rows, widths that force an item-level view,
+    and a pre-filled arena whose padding must survive."""
+    from repro.storage.layout import gather_docs_at
+    kw, t_max, ids, rows_kind, fill = _GATHER_CASES[case]
+    layout = _gather_layout(**kw)
+    ids = np.asarray(ids, np.int64)
+    n_rows = 2 * len(ids) + 3
+    rows = np.arange(len(ids))
+    if rows_kind == "shuffled":   # interleaved, out of order, with gaps
+        rows = np.random.default_rng(1).permutation(n_rows)[:len(ids)]
+    want = _arena(layout, n_rows, t_max, fill)
+    _unpack_reference(layout, ids, rows, *want)
+    got = _arena(layout, n_rows, t_max, fill)
+    gather_docs_at(layout, ids, rows, *got)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+    for r, i in zip(rows, ids):   # padding past each length is untouched
+        t = min(int(layout.n_tokens[i]), t_max)
+        assert int(got[2][r]) == t
+        assert (got[1][r, t:] == fill).all()
+    untouched = np.setdiff1d(np.arange(n_rows), rows)
+    assert (got[2][untouched] == -1).all()
+    assert (got[1][untouched] == fill).all()
+
+
+def test_bulk_gather_never_unpacks_per_passage(monkeypatch):
+    """A 500-passage run is gathered with ``unpack_doc`` made to raise, so a
+    per-passage loop cannot come back unnoticed."""
+    import repro.storage.layout as L
+    layout = _gather_layout(n=600, seed=11)
+    ids = np.arange(50, 550)
+    want = _arena(layout, len(ids), 48, 0.0)
+    _unpack_reference(layout, ids, np.arange(len(ids)), *want)
+
+    def no_unpack(*a, **k):
+        raise AssertionError("gather fell back to a per-passage unpack_doc")
+
+    monkeypatch.setattr(L, "unpack_doc", no_unpack)
+    got = _arena(layout, len(ids), 48, 0.0)
+    L.gather_docs_into(layout, ids, *got)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
